@@ -125,6 +125,10 @@ def main():
     from repro.kernels import tune
 
     backend = jax.default_backend()
+    if backend != "tpu" and not args.smoke:
+        raise SystemExit(f"kernel_bench: no TPU (backend {backend!r}); "
+                         "full-size timings come only from the chip — "
+                         "pass --smoke for the interpret-mode CPU sweep")
     tune_impl = "pallas" if backend == "tpu" else "interpret"
 
     if args.smoke:

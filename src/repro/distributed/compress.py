@@ -29,11 +29,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-try:                               # jax >= 0.6
-    _shard_map = jax.shard_map
-except AttributeError:             # 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from ..compression.q8 import q8_decode, q8_encode
 
 
@@ -88,7 +83,7 @@ def cross_pod_psum_compressed(x: jnp.ndarray, mesh,
             f"{x.shape[0] if x.ndim else 'scalar'}")
     in_spec = jax.sharding.PartitionSpec(pod_axis)
 
-    @partial(_shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(in_spec,), out_specs=in_spec)
     def inner(xp):
         # xp (1, ...): this pod's contribution; drop the size-1 pod slice

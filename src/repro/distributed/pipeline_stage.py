@@ -23,11 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:                               # jax >= 0.6
-    _shard_map = jax.shard_map
-except AttributeError:             # 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def gpipe_apply(stage_fn: Callable, stage_params, x_mb: jnp.ndarray,
                 mesh, axis: str = "pod") -> jnp.ndarray:
@@ -44,7 +39,7 @@ def gpipe_apply(stage_fn: Callable, stage_params, x_mb: jnp.ndarray,
 
     param_specs = jax.tree.map(lambda _: P(axis), stage_params)
 
-    @partial(_shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(param_specs, P()), out_specs=P(axis))
     def run(params_local, x_all):
         sid = lax.axis_index(axis)

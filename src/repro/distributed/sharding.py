@@ -230,8 +230,14 @@ def activation_sharding(mesh, rules=None):
         _CTX.active = prev
 
 
+def active_mesh():
+    """``(mesh, rules)`` of the enclosing :func:`activation_sharding`
+    context, or None outside one."""
+    return getattr(_CTX, "active", None)
+
+
 def constrain(x, *logical_axes):
-    active = getattr(_CTX, "active", None)
+    active = active_mesh()
     if active is None:
         return x
     mesh, rules = active

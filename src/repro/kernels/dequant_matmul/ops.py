@@ -216,7 +216,8 @@ def _dequant_matmul_spec() -> OpSpec:
         name="dequant_matmul",
         impls={
             "pallas": Impl("pallas", _run_pallas, platforms=("tpu",)),
-            "interpret": Impl("interpret", _run_interpret),
+            "interpret": Impl("interpret", _run_interpret,
+                              platforms=("cpu",)),
             "ref": Impl("ref", _run_ref, uses_tiles=False),
         },
         defaults={"tpu": "pallas", "*": "ref"},
@@ -275,7 +276,8 @@ def _dequant_matmul_grouped_spec() -> OpSpec:
         impls={
             "pallas": Impl("pallas", _run_grouped_pallas,
                            platforms=("tpu",)),
-            "interpret": Impl("interpret", _run_grouped_interpret),
+            "interpret": Impl("interpret", _run_grouped_interpret,
+                              platforms=("cpu",)),
             "ref": Impl("ref", _run_grouped_ref, uses_tiles=False),
         },
         defaults={"tpu": "pallas", "*": "ref"},

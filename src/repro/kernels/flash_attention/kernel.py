@@ -23,6 +23,10 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 DEFAULT_BQ = 256
 DEFAULT_BK = 512
+# one MXU pass on the operands' own dtype, whatever
+# jax.default_matmul_precision says: under "highest", bf16 operands would
+# ask Mosaic for an fp32 contraction, which it refuses ("Bad lhs type")
+_PRECISION = jax.lax.Precision.DEFAULT
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
@@ -45,7 +49,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         k = k_ref[0]                      # (bk, d)
         v = v_ref[0]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, k, (((1,), (1,)), ((), ())), precision=_PRECISION,
             preferred_element_type=jnp.float32) * scale
         if causal:
             rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
@@ -59,7 +63,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            precision=_PRECISION, preferred_element_type=jnp.float32)
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 

@@ -43,13 +43,35 @@ def pick_block(pref: int, size: int, floor: int = 8) -> int | None:
     return None
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_kernel(qf, kf, vf, causal, bq, bk, interpret):
+    """The Pallas kernel made differentiable: ``pallas_call`` has no JVP,
+    so the backward pass recomputes attention with the pure-jnp reference
+    and differentiates that (a train step on TPU runs the kernel forward)."""
+    return flash_attention_pallas(qf, kf, vf, causal=causal, bq=bq, bk=bk,
+                                  interpret=interpret)
+
+
+def _flash_kernel_fwd(qf, kf, vf, causal, bq, bk, interpret):
+    out = _flash_kernel(qf, kf, vf, causal, bq, bk, interpret)
+    return out, (qf, kf, vf)
+
+
+def _flash_kernel_bwd(causal, bq, bk, interpret, res, g):
+    _, vjp = jax.vjp(functools.partial(flash_attention_ref, causal=causal),
+                     *res)
+    return vjp(g)
+
+
+_flash_kernel.defvjp(_flash_kernel_fwd, _flash_kernel_bwd)
+
+
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk",
                                               "interpret", "use_ref"))
 def _flash(qf, kf, vf, *, causal, bq, bk, interpret, use_ref):
     if use_ref:
         return flash_attention_ref(qf, kf, vf, causal=causal)
-    return flash_attention_pallas(qf, kf, vf, causal=causal, bq=bq, bk=bk,
-                                  interpret=interpret)
+    return _flash_kernel(qf, kf, vf, causal, bq, bk, interpret)
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
@@ -194,7 +216,7 @@ def _flash_attention_spec() -> OpSpec:
         impls={
             "pallas": Impl("pallas", _run_pallas, platforms=("tpu",),
                            constraint=_pallas_constraint),
-            "interpret": Impl("interpret", _run_interpret,
+            "interpret": Impl("interpret", _run_interpret, platforms=("cpu",),
                               constraint=_pallas_constraint),
             "scan": Impl("scan", _run_scan, uses_tiles=False),
             "ref": Impl("ref", _run_ref, uses_tiles=False),

@@ -3,8 +3,9 @@
 Tiling: the flattened weight tensor is viewed as (M, LANES) with
 LANES = 1024 (8 sublanes x 128 lanes); each grid step processes a
 (BLOCK_M, 1024) tile of w / fisher / prev_sig resident in VMEM
-(3 x 1 MB in + 1 MB out at BLOCK_M = 256, f32), leaving headroom for the
-unrolled candidate loop.  The rate model arrives as two tiny replicated
+(3 x 512 KB in + 512 KB out at BLOCK_M = 128, f32, double-buffered),
+leaving headroom for the unrolled candidate loop.  256-row tiles need
+16.8 MB of scoped VMEM, over the 16 MB the v5e compiler allows.  The rate model arrives as two tiny replicated
 coefficient rows (see coeffs.py) so no dynamic gather is needed — the
 magnitude-class select unrolls into compare/selects on the VPU.
 """
@@ -22,7 +23,7 @@ from .coeffs import (SC_L0_SIG0, SC_L0_SIG1, SC_L1_SIG0, SC_L1_SIG1, SC_LNEG,
                      SC_LPOS)
 
 LANES = 1024
-BLOCK_M = 256
+BLOCK_M = 128
 
 
 def _floor_log2(i: jnp.ndarray) -> jnp.ndarray:

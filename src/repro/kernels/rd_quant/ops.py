@@ -25,7 +25,7 @@ pack_rate_params = pack_coeffs
 
 def default_block_m(n: int) -> int:
     """Row-block clamped to the sublane-padded row count: small tensors
-    (< BLOCK_M * LANES elements) stop padding up to the full 256-row tile."""
+    (< BLOCK_M * LANES elements) stop padding up to the full row tile."""
     rows = -(-max(int(n), 1) // LANES)
     return min(BLOCK_M, -(-rows // 8) * 8)
 
@@ -135,12 +135,13 @@ def _rd_quant_spec() -> OpSpec:
         name="rd_quant",
         impls={
             "pallas": Impl("pallas", _run_pallas, platforms=("tpu",)),
-            "interpret": Impl("interpret", _run_interpret),
+            "interpret": Impl("interpret", _run_interpret,
+                              platforms=("cpu",)),
             "ref": Impl("ref", _run_ref, uses_tiles=False),
         },
         defaults={"tpu": "pallas", "*": "ref"},
         fallbacks=("ref",),
-        tile_space={"block_m": (8, 64, 128, 256, 512)},
+        tile_space={"block_m": (8, 64, 128)},
         default_tiles=lambda s: {"block_m": default_block_m(s["n"])},
         shape_info=_shape_info,
         bucket=_bucket,

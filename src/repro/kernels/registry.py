@@ -10,7 +10,9 @@ Call sites then do::
     out = kernels.get("dequant_matmul")(x, w_q, scale, policy=cfg.kernels)
 
 and dispatch picks the implementation by platform (TPU -> pallas,
-CPU -> interpret/ref), honors a single :class:`KernelPolicy`, consults the
+CPU -> ref/scan; the Pallas ``interpret`` impls are CPU-only, so no
+fallback chain on a TPU reaches interpret mode), honors a single
+:class:`KernelPolicy`, consults the
 persistent tuning cache (:mod:`repro.kernels.tune`) for tile parameters at
 trace time, and surfaces every constraint-driven fallback through
 :func:`dispatch_report` instead of downgrading silently.  Requesting an

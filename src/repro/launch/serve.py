@@ -7,9 +7,15 @@ pluggable weight backend, optionally from a DeepCABAC container.
 
 ``--backend``: ``bf16`` (full-precision weights), ``q8`` (in-memory int8
 fixed-point matmul weights), ``container`` (stream-decode the DCBC blob;
-serve-q8 records stay int8).  Without ``--ckpt`` the bf16/q8 backends use
-random init; the container backend packs a serve-q8 container in-process
-first so the streaming load path is still exercised.
+serve-q8 records stay int8).  Without ``--ckpt`` the weights are random,
+made by jitted programs on the default device (:func:`random_weights`);
+the container backend packs that tree with the serve-q8 codec
+in-process so the streaming load path still runs.
+
+:func:`random_weights` and :func:`serve_requests` are the serving path
+``chip_smoke.py`` drives as well — there is no second one.  ``main``
+first calls :func:`~repro.launch.runtime.configure_runtime` (compile
+cache, strict bf16 rounding), as every entry point does.
 """
 
 from __future__ import annotations
@@ -25,7 +31,49 @@ from .. import configs
 from ..configs import ARCH_IDS
 from ..models.transformer import init_params
 from ..serve.backends import available_backends
+from ..serve.quantized import quantize_tree_q8
 from ..serve.session import ServeConfig, ServeSession
+from .runtime import configure_runtime
+
+
+def random_weights(cfg, backend: str):
+    """Random-init weight source for ``backend`` (seed 0), built on the
+    default device by one jitted program so no eager f32 transient (or,
+    for q8, the full-precision tree) ever sits in device memory:
+
+    ``bf16``       the ``init_params`` tree;
+    ``q8``         ``quantize_tree_q8(init_params(...))`` — the q8 backend
+                   loads it as-is (the tree pass is idempotent);
+    ``container``  the bf16 tree pulled to the host and packed by the
+                   ``serve-q8`` codec (bytes), as users' containers are.
+    """
+    key = jax.random.PRNGKey(0)
+    init = jax.jit(lambda k: init_params(cfg, k))
+    if backend == "bf16":
+        return init(key)
+    if backend == "q8":
+        return jax.jit(lambda k: quantize_tree_q8(init_params(cfg, k)))(key)
+    if backend == "container":
+        from .. import compression
+        return compression.get("serve-q8").compress(
+            jax.device_get(init(key))).blob
+    raise ValueError(f"no random init for backend {backend!r}")
+
+
+def serve_requests(cfg, weights, prompts, *, backend: str,
+                   serve_cfg: ServeConfig, max_new_tokens: int,
+                   temperature: float = 0.0):
+    """Load ``weights`` through ``backend`` into a :class:`ServeSession`,
+    submit one request per prompt row and run them to completion.
+
+    Returns ``(tokens (n, max_new_tokens) int32, session)``; the session
+    keeps the loaded weights and caches alive until the caller drops it."""
+    session = ServeSession(cfg, weights, backend=backend,
+                           serve_cfg=serve_cfg)
+    handles = [session.submit(p, max_new_tokens=max_new_tokens,
+                              temperature=temperature) for p in prompts]
+    session.run()
+    return np.stack([h.result() for h in handles]), session
 
 
 def main():
@@ -45,7 +93,7 @@ def main():
     ap.add_argument("--kernel-impl", action="append", default=[],
                     metavar="OP=IMPL",
                     help="pin a kernel impl (repeatable), e.g. "
-                         "flash_attention=pallas dequant_matmul=interpret")
+                         "flash_attention=pallas dequant_matmul=ref")
     ap.add_argument("--strict-kernels", action="store_true",
                     help="a pinned impl that cannot run raises instead of "
                          "falling back (see kernels.dispatch_report)")
@@ -53,6 +101,7 @@ def main():
                     help="ignore the persistent kernel tuning cache")
     args = ap.parse_args()
 
+    configure_runtime()
     cfg = configs.get(args.arch, smoke=args.smoke)
     pol = cfg.kernels
     for pin in args.kernel_impl:
@@ -67,35 +116,27 @@ def main():
     pol = dataclasses.replace(pol, strict=args.strict_kernels,
                               use_tuning_cache=not args.no_tuning_cache)
     cfg = cfg.replace(kernels=pol)
-    max_len = args.prompt_len + args.steps
     if args.ckpt:
         with open(args.ckpt, "rb") as f:
             weights = f.read()
-    elif args.backend == "container":
-        from .. import compression
-        params = init_params(cfg, jax.random.PRNGKey(0))
-        weights = compression.get("serve-q8").compress(params).blob
-        print(f"packed serve-q8 container in-process: "
-              f"{len(weights) / 2**20:.1f} MiB")
     else:
-        weights = init_params(cfg, jax.random.PRNGKey(0))
+        weights = random_weights(cfg, args.backend)
+        if args.backend == "container":
+            print(f"packed serve-q8 container in-process: "
+                  f"{len(weights) / 2**20:.1f} MiB")
 
-    scfg = ServeConfig(slots=args.slots or args.batch, max_len=max_len)
-    session = ServeSession(cfg, weights, backend=args.backend,
-                           serve_cfg=scfg)
-    rng = np.random.default_rng(0)
-    prompts = rng.integers(0, cfg.vocab_size,
-                           (args.batch, args.prompt_len)).astype(np.int32)
-    handles = [session.submit(p, max_new_tokens=args.steps,
-                              temperature=args.temperature)
-               for p in prompts]
-    session.run()
-    out = np.stack([h.result() for h in handles])
+    scfg = ServeConfig(slots=args.slots or args.batch,
+                       max_len=args.prompt_len + args.steps)
+    prompts = np.random.default_rng(0).integers(
+        0, cfg.vocab_size, (args.batch, args.prompt_len)).astype(np.int32)
+    out, _ = serve_requests(cfg, weights, prompts, backend=args.backend,
+                            serve_cfg=scfg, max_new_tokens=args.steps,
+                            temperature=args.temperature)
     print(f"backend={args.backend} slots={scfg.slots}: generated "
           f"{out.shape} tokens; first row tail: "
           f"{out[0, -min(16, out.shape[1]):].tolist()}")
     for rec in kernels.dispatch_report():
-        print(f"kernel fallback: {rec['op']}: "
+        print(f"kernel {rec['kind']}: {rec['op']}: "
               f"{rec['requested'] or 'default'} -> {rec['impl']} "
               f"({rec['reason']})")
 

@@ -22,6 +22,7 @@ from ..configs import ARCH_IDS
 from ..distributed.compress import CompressionConfig
 from ..optim.adamw import AdamWConfig
 from ..train.loop import LoopConfig, train_loop
+from .runtime import configure_runtime
 from .mesh import make_local_mesh, make_production_mesh
 
 
@@ -40,6 +41,7 @@ def main():
     ap.add_argument("--multi-pod", action="store_true")
     args = ap.parse_args()
 
+    configure_runtime()
     cfg = configs.get(args.arch, smoke=args.smoke)
     if args.production_mesh:
         mesh = make_production_mesh(multi_pod=args.multi_pod)

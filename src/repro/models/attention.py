@@ -11,11 +11,13 @@ pinned impl meets ``KernelPolicy(strict=True)``.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 from .. import kernels as _kernels
-from ..distributed.sharding import constrain
+from ..distributed.sharding import active_mesh, constrain, spec_for
 from ..serve.quantized import dequant_cache_value, quantize_cache_value
 from .layers import apply_m_rope, apply_rope, q8_einsum, rms_norm
 
@@ -108,8 +110,42 @@ def attend(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                 f"unknown attention impl {impl!r}; "
                 f"one of {sorted(_ATTN_IMPLS)}")
         policy = policy.override("flash_attention", _ATTN_IMPLS[impl])
-    return _kernels.get("flash_attention")(q, k, v, qpos, kv_block=kv_block,
-                                           kv_len=kv_len, policy=policy)
+
+    flash = _kernels.get("flash_attention")
+
+    def op(q, k, v, qpos, kv_len):
+        return flash(q, k, v, qpos, kv_block=kv_block, kv_len=kv_len,
+                     policy=policy)
+    active = active_mesh()
+    if active is None or flash.plan(
+            q, k, v, qpos, kv_block=kv_block, kv_len=kv_len,
+            policy=policy).impl != "pallas":
+        return op(q, k, v, qpos, kv_len)
+    return _attend_on_mesh(op, q, k, v, qpos, kv_len, *active)
+
+
+def _attend_on_mesh(op, q, k, v, qpos, kv_len, mesh, rules):
+    """The Mosaic kernel under an activation mesh.  XLA cannot partition
+    a Pallas kernel, so it runs inside ``shard_map`` over batch rows and
+    query heads — attention is independent per row and per head — and
+    only ever sees local shards (the other impls are plain XLA, which the
+    partitioner splits itself).  When the KV heads cannot split like the
+    query heads, each query head gets its own copy of its KV group (the
+    kernel repeats groups the same way), so heads still split."""
+    qs = spec_for(q.shape, ("batch", None, "heads", None), mesh, rules)
+    ks = spec_for(k.shape, ("batch", None, "kv_heads", None), mesh, rules)
+    if qs[2] is not None and ks[2] != qs[2]:
+        rep = q.shape[2] // k.shape[2]
+        k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+    qs = P(qs[0], None, qs[2], None)
+    if kv_len is None:
+        return jax.shard_map(
+            lambda q, k, v, qpos: op(q, k, v, qpos, None), mesh=mesh,
+            in_specs=(qs, qs, qs, P(qs[0], None)), out_specs=qs,
+            check_vma=False)(q, k, v, qpos)
+    return jax.shard_map(
+        op, mesh=mesh, in_specs=(qs, qs, qs, P(qs[0], None), P(qs[0])),
+        out_specs=qs, check_vma=False)(q, k, v, qpos, kv_len)
 
 
 # ---------------------------------------------------------------------------

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..distributed.sharding import SERVE_RULES, activation_sharding
 from ..models.config import ModelConfig
 from ..models.transformer import decode_step, forward, init_cache, prefill
 from .backends import _insert, resolve_backend
@@ -171,7 +172,7 @@ class ServeSession:
                 restore_workers=serve_cfg.kv_restore_workers)
             self._resume_q: deque = deque()     # (req, parked, pos, next)
             self._parked: dict = {}             # manual parks, by req id
-            self._decode_paged = jax.jit(
+            self._decode_paged = self._jit(
                 lambda p, pools, pages, tok, pos: decode_step(
                     p, cfg, pools, pos, tokens=tok, cache_pages=pages))
             self._prefill_fns: dict = {}        # cache_len -> jit
@@ -181,7 +182,7 @@ class ServeSession:
         else:
             self._kv = None
             self._caches = init_cache(cfg, serve_cfg.slots, max_len)
-            self._prefill = jax.jit(
+            self._prefill = self._jit(
                 lambda p, toks: prefill(p, cfg, tokens=toks,
                                         max_len=max_len))
 
@@ -194,11 +195,25 @@ class ServeSession:
                                                 caches=caches,
                                                 last_index=last_idx)
                 return logits[:, 0, :], new_caches
-            self._prefill_padded = jax.jit(prefill_padded)
-            self._decode = jax.jit(
+            self._prefill_padded = self._jit(prefill_padded)
+            self._decode = self._jit(
                 lambda p, caches, tok, pos: decode_step(p, cfg, caches, pos,
                                                         tokens=tok))
             self._scatter = jax.jit(self._scatter_impl)
+
+    def _jit(self, fn):
+        """``jax.jit`` of a model step.  When the backend placed the
+        weights on a serving mesh, tracing runs under that mesh's serve
+        sharding rules, so activation constraints and mesh-aware attention
+        resolve against the devices the weights live on."""
+        mesh = self.backend.mesh
+        if mesh is None:
+            return jax.jit(fn)
+
+        def on_mesh(*args):
+            with activation_sharding(mesh, SERVE_RULES):
+                return fn(*args)
+        return jax.jit(on_mesh)
 
     @classmethod
     def from_container(cls, cfg: ModelConfig, blob: bytes, *,
@@ -637,8 +652,8 @@ class ServeSession:
         fn = self._prefill_fns.get(cache_len)
         if fn is None:
             cfg = self.cfg
-            fn = jax.jit(lambda p, toks: prefill(p, cfg, tokens=toks,
-                                                 max_len=cache_len))
+            fn = self._jit(lambda p, toks: prefill(p, cfg, tokens=toks,
+                                                   max_len=cache_len))
             self._prefill_fns[cache_len] = fn
         return fn
 
@@ -653,7 +668,7 @@ class ServeSession:
                                                 caches=caches,
                                                 last_index=last_idx)
                 return logits[:, 0, :], new_caches
-            fn = jax.jit(pad_fn)
+            fn = self._jit(pad_fn)
             self._prefill_pad_fns[cache_len] = fn
         return fn
 
@@ -683,7 +698,7 @@ class ServeSession:
                     return pool.at[:, ids[n_ctx:]].set(
                         c[:, n_ctx:].astype(pool.dtype))
                 return logits[:, 0], jax.tree.map(put, pools, newc)
-            fn = jax.jit(partial_fn)
+            fn = self._jit(partial_fn)
             self._partial_fns[n_ctx] = fn
         return fn
 

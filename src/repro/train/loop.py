@@ -90,12 +90,12 @@ def train_loop(cfg: ModelConfig, mesh, loop: LoopConfig,
                 if dt > loop.straggler_factor * med:
                     result.straggler_steps.append(step)
             if mgr and (step + 1) % loop.ckpt_every == 0:
-                mgr.save(state, step + 1)
+                mgr.save(state, step + 1, mesh=mesh)
             if stop["flag"]:
                 break
         result.final_step = int(jax.device_get(state["step"]))
         if mgr:
-            mgr.save(state, result.final_step)
+            mgr.save(state, result.final_step, mesh=mesh)
             mgr.wait()
     finally:
         signal.signal(signal.SIGTERM, prev_handler)

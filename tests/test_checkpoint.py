@@ -11,6 +11,7 @@ import pytest
 from repro.checkpoint.manager import (CheckpointConfig, CheckpointManager,
                                       flatten_tree, unflatten_like)
 from repro.configs import get_smoke_config
+from repro.launch.mesh import make_local_mesh
 from repro.distributed.sharding import build_param_specs, named_shardings
 from repro.models.transformer import init_params
 from repro.optim.adamw import AdamWConfig
@@ -119,7 +120,7 @@ def test_elastic_resharded_restore(tmp_path):
     mgr = CheckpointManager(CheckpointConfig(str(tmp_path),
                                              params_mode="raw"))
     mgr.save(state, 3)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh(1, 1)
     shardings = {
         "params": named_shardings(
             build_param_specs(state["params"], mesh), mesh),

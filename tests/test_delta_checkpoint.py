@@ -19,6 +19,7 @@ from repro.checkpoint.delta import DeltaChainError
 from repro.checkpoint import sharded
 from repro.checkpoint.sharded import MeshSpec
 from repro.configs import get_smoke_config
+from repro.launch.mesh import make_local_mesh
 from repro.core.cabac_vec import resolve_backend
 from repro.core.codec import DecodeOptions, QuantizedTensor
 from repro.models.transformer import init_params
@@ -243,7 +244,7 @@ def test_delta_chain_restores_across_mesh_reshape(tmp_path):
     assert _meta(mgr, 2)["kind"] == "delta"
 
     ref = delta.restore_flat_delta(mgr.cfg.directory, 2)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh(1, 1)
     on_mesh = delta.restore_on_mesh_delta(mgr.cfg.directory, 2, mesh)
     assert sorted(on_mesh) == sorted(ref)
     for k, arr in on_mesh.items():

@@ -21,6 +21,7 @@ import numpy as np
 
 from repro.configs import get_smoke_config
 from repro.launch.dryrun import analyze, collective_bytes
+from repro.launch.mesh import make_local_mesh
 from repro.distributed.compress import cross_pod_psum_compressed
 from repro.distributed.sharding import DEFAULT_RULES
 from repro.optim.adamw import AdamWConfig
@@ -29,7 +30,7 @@ from repro.train.steps import (batch_specs, init_train_state,
                                make_train_step, state_specs)
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_local_mesh(data=2, model=2, pod=2)
 cfg = get_smoke_config("llama3-8b").replace(
     num_heads=4, num_kv_heads=2, d_model=128, d_ff=256)
 ocfg, ccfg = AdamWConfig(), CompressionConfig(enabled=True)

@@ -30,6 +30,26 @@ def test_flash_vs_ref(b, sq, skv, h, g, d, dtype):
     np.testing.assert_allclose(out, ref, atol=tol, rtol=tol)
 
 
+def test_flash_kernel_is_differentiable():
+    """A train step on TPU runs the kernel forward: its gradient (reference
+    backward through the custom VJP) matches the oracle's."""
+    rng = np.random.default_rng(3)
+    b, s, h, g, d = 1, 64, 2, 1, 32
+    q = jnp.asarray(rng.standard_normal((b, s, h, d)) * 0.5, jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, s, g, d)) * 0.5, jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, s, g, d)), jnp.float32)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v)))
+    kern = jax.grad(loss(lambda *a: flash_attention(
+        *a, interpret=True, bq=32, bk=32)), argnums=(0, 1, 2))(q, k, v)
+    ref = jax.grad(loss(lambda *a: flash_attention(*a, use_ref=True)),
+                   argnums=(0, 1, 2))(q, k, v)
+    for got, want in zip(kern, ref):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5, rtol=1e-5)
+
+
 def test_flash_matches_scan_attend():
     """The kernel and the model's scan-flash path agree (same math)."""
     rng = np.random.default_rng(7)
@@ -102,7 +122,61 @@ def test_pallas_flash_kv_len_strict_raises():
     v8 = v[..., :8]
     with pytest.raises(kernels.KernelDispatchError, match="d != dv"):
         attend(q, k, v8, qpos, policy=pol)
+    # interpret mode is CPU-only: on tpu a strict interpret pin refuses
+    # instead of hiding the device behind the interpreter
+    with pytest.raises(kernels.KernelDispatchError, match="platform 'tpu'"):
+        attend(q, k, v, qpos, policy=pol.override(
+            "flash_attention", "interpret"))
     # but a satisfiable strict request runs
-    out = attend(q, k, v, qpos, policy=pol.override(
+    cpu_pol = kernels.KernelPolicy(platform="cpu", strict=True)
+    out = attend(q, k, v, qpos, policy=cpu_pol.override(
         "flash_attention", "interpret"))
     assert out.shape == q.shape
+
+
+_MESH_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax
+import jax.numpy as jnp
+import numpy as np
+from repro import kernels
+from repro.distributed.sharding import SERVE_RULES
+from repro.launch.mesh import make_local_mesh
+from repro.models.attention import _attend_on_mesh
+
+mesh = make_local_mesh(data=1, model=4)
+pol = kernels.KernelPolicy().override("flash_attention", "ref")
+op = lambda q, k, v, qpos, kv_len: kernels.get("flash_attention")(
+    q, k, v, qpos, kv_len=kv_len, policy=pol)
+rng = np.random.default_rng(0)
+for h, g in [(8, 8), (8, 2)]:      # heads split like KV / KV cannot split
+    q = jnp.asarray(rng.standard_normal((2, 16, h, 32)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((2, 16, g, 32)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((2, 16, g, 32)), jnp.float32)
+    qpos = jnp.broadcast_to(jnp.arange(16, dtype=jnp.int32), (2, 16))
+    for kv_len in (None, jnp.asarray([16, 9], jnp.int32)):
+        want = op(q, k, v, qpos, kv_len)
+        got = jax.jit(lambda *a: _attend_on_mesh(op, *a, mesh, SERVE_RULES)
+                      )(q, k, v, qpos, kv_len)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+        assert got.sharding.spec[2] == "model", got.sharding.spec
+print("MESH_ATTEND_OK")
+"""
+
+
+def test_attend_on_mesh_splits_heads():
+    """The shard_map wrapper that keeps a Pallas kernel on local shards:
+    same result as unsharded attention, heads split over the model axis
+    even when the KV groups cannot split (4 fake CPU devices)."""
+    import os
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, PYTHONPATH=src + os.pathsep
+               + os.environ.get("PYTHONPATH", ""))
+    proc = subprocess.run([sys.executable, "-c", _MESH_SCRIPT], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "MESH_ATTEND_OK" in proc.stdout

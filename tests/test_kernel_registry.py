@@ -234,6 +234,27 @@ def test_decode_routes_to_scan_without_fallback_record():
             if r["op"] == "flash_attention"] == []
 
 
+@pytest.mark.parametrize("op", ["dequant_matmul", "dequant_matmul_grouped",
+                                "flash_attention", "rd_quant"])
+def test_interpret_never_dispatched_on_tpu(op):
+    """Interpret mode is CPU-only: a tpu plan — default, pinned pallas, or
+    pinned interpret — never resolves to it, so a refused kernel cannot
+    quietly run in the interpreter on the chip."""
+    spec = kernels.spec(op)
+    assert spec.impls["interpret"].platforms == ("cpu",)
+    shape = {"dequant_matmul": (8, 256, 256),
+             "dequant_matmul_grouped": (2, 8, 256, 256),
+             "flash_attention": (1, 64, 64, 2, 2, 32),
+             "rd_quant": (1 << 10,)}[op]
+    args, kwargs = spec.example_inputs(shape)
+    bound = kernels.get(op)
+    tpu = KernelPolicy(platform="tpu")
+    for pol in (tpu, tpu.override(op, "pallas"), tpu.override(op, "interpret")):
+        assert bound.plan(*args, policy=pol, **kwargs).impl != "interpret"
+    cpu = KernelPolicy(platform="cpu").override(op, "interpret")
+    assert bound.plan(*args, policy=cpu, **kwargs).impl == "interpret"
+
+
 def test_attend_impl_aliases_map_to_registry():
     """attend(impl=...) keeps its historical vocabulary, mapped onto
     registry impl names (the ModelConfig string fields are gone)."""

@@ -17,7 +17,8 @@ import jax.numpy as jnp
 import numpy as np
 from repro.distributed.pipeline_stage import gpipe_apply, split_stages
 
-mesh = jax.make_mesh((4, 2), ("pod", "data"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("pod", "data"))
 S, L, M, MB, D = 4, 8, 6, 4, 32
 rng = np.random.default_rng(0)
 layers = {"w": jnp.asarray(rng.standard_normal((L, D, D)) * (D ** -0.5)),

@@ -17,6 +17,7 @@ from repro.checkpoint.manager import CheckpointConfig, CheckpointManager
 from repro.checkpoint.sharded import MeshSpec
 from repro.compression.tree import flatten_tree
 from repro.configs import get_smoke_config
+from repro.launch.mesh import make_local_mesh
 from repro.optim.adamw import AdamWConfig
 from repro.train.steps import init_train_state
 
@@ -107,7 +108,7 @@ def test_restore_on_mesh_in_process(tmp_path):
     """mesh= restore returns mesh-sharded jax Arrays, bit-identical."""
     cfg, state = _state()
     mono, shard = _save_both(tmp_path, state, save_shards=2)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh(1, 1)
     r_mesh, _ = shard.restore(state, mesh=mesh)
     r_mono, _ = mono.restore(state)
     leaves = jax.tree.leaves(r_mesh["params"])
@@ -189,7 +190,7 @@ def test_restore_mesh_on_monolithic_checkpoint_errors(tmp_path):
     mono = CheckpointManager(CheckpointConfig(
         str(tmp_path), codec="deepcabac-v3", delta_rel=1e-3))
     mono.save(state, 1)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh(1, 1)
     with pytest.raises(ValueError, match="sharded checkpoint"):
         mono.restore(state, mesh=mesh)
 
@@ -254,7 +255,7 @@ def test_bf16_backend_manifest_on_mesh(tmp_path):
     with open(os.path.join(d, sharded.MANIFEST_NAME), "w") as f:
         json.dump(manifest, f)
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh(1, 1)
     tree = Bf16Backend(mesh=mesh).load(cfg, d)
     leaves = jax.tree.leaves(tree)
     assert all(isinstance(x, jax.Array) for x in leaves)
@@ -276,6 +277,7 @@ import numpy as np
 from repro.checkpoint.manager import CheckpointConfig, CheckpointManager
 from repro.checkpoint.sharded import MeshSpec
 from repro.configs import get_smoke_config
+from repro.launch.mesh import make_local_mesh
 from repro.optim.adamw import AdamWConfig
 from repro.train.steps import init_train_state
 
@@ -293,7 +295,7 @@ with tempfile.TemporaryDirectory() as td:
     mgr.save(state, 1, mesh=MeshSpec(("data", "model"), (4, 1)))
     # ... restore on 1-, 2- and 8-device meshes
     for shape in [(1, 1), (2, 1), (4, 2)]:
-        mesh = jax.make_mesh(shape, ("data", "model"))
+        mesh = make_local_mesh(*shape)
         restored, _ = mgr.restore(state, mesh=mesh)
         leaves = jax.tree.leaves(restored["params"])
         assert all(isinstance(x, jax.Array) for x in leaves)

@@ -89,3 +89,70 @@ def test_dc_v1_with_empirical_fisher(trained):
     assert res.report["bits_per_param"] < 32
     rec = res.reconstructed()
     assert set(rec) == set(flat_p)
+
+
+@pytest.mark.parametrize("env_dir", [None, "cache-from-env"])
+def test_compile_cache_location(tmp_path, monkeypatch, env_dir):
+    """Entry points keep JAX's compile cache where
+    $JAX_COMPILATION_CACHE_DIR says, else at the checkout's fixed
+    .jax_cache — never a temp, PID or time-based path."""
+    import jax
+    from repro.launch import runtime
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv(runtime.ENV_VAR, raising=False)
+        want = str(runtime.CHECKOUT_CACHE)
+    else:
+        monkeypatch.setenv(runtime.ENV_VAR, str(tmp_path / env_dir))
+        want = str(tmp_path / env_dir)
+    try:
+        assert runtime.configure_compile_cache() == want
+        assert runtime.configure_compile_cache() == want   # stable
+        if env_dir is None:
+            assert jax.config.jax_compilation_cache_dir == want
+            assert (runtime.CHECKOUT_CACHE.parent / "src" / "repro"
+                    ).is_dir()
+        else:   # JAX reads the variable itself; the code sets nothing
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_strict_rounding_reaches_xla(tmp_path):
+    """An entry point's configure_runtime sets strict rounding before
+    the backend starts, so every program the process compiles uses it."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+    script = (
+        "import os\n"
+        "from repro.launch.runtime import STRICT_ROUNDING, configure_runtime\n"
+        "import jax\n"
+        "configure_runtime()\n"
+        "assert os.environ['XLA_FLAGS'].split().count(STRICT_ROUNDING) == 1\n"
+        "configure_runtime()   # idempotent\n"
+        "assert os.environ['XLA_FLAGS'].split().count(STRICT_ROUNDING) == 1\n"
+        "print(float(jax.jit(lambda x: x * 2)(1.5)))\n")
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("XLA_FLAGS", "JAX_COMPILATION_CACHE_DIR")}
+    env.update(PYTHONPATH=src, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+    out = subprocess.run([sys.executable, "-c", script], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip() == "3.0"
+
+
+def test_strict_rounding_refuses_a_started_backend(monkeypatch):
+    """XLA reads XLA_FLAGS once: setting the flag after the backend
+    started would silently not apply, so it raises instead."""
+    import jax
+    from repro.launch import runtime
+    jax.devices()
+    monkeypatch.setenv("XLA_FLAGS", "")
+    with pytest.raises(RuntimeError, match="before the first JAX"):
+        runtime.set_strict_rounding()
+    monkeypatch.setenv("XLA_FLAGS", runtime.STRICT_ROUNDING)
+    runtime.set_strict_rounding()             # already set: nothing to do
