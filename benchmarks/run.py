@@ -125,7 +125,7 @@ def bench_rd_quant_kernel(fast: bool):
          {"weights_per_s": n / (t1 - t0), "n": n,
           "impl": rd_quant.plan(w, None, probs, step=0.01, lam=1e-4).impl})
     # pallas interpret path — correctness-path timing only (Python-level;
-    # the TPU perf story lives in the roofline analysis)
+    # TPU times come from the chip benchmark, benchmarks/chip)
     interp = kernels.KernelPolicy().override("rd_quant", "interpret")
     n2 = 1 << 15
     t0 = time.time()

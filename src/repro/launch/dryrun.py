@@ -13,8 +13,9 @@ and records:
   - cost_analysis()             (HLO FLOPs / bytes for the roofline)
   - collective bytes            (parsed from the post-SPMD HLO text)
 
-Results land in benchmarks/results/dryrun/<arch>__<shape>__<mesh>.json; the
-roofline report (benchmarks/roofline.py) reads them.
+Results land in benchmarks/results/dryrun/<arch>__<shape>__<mesh>.json.
+They are compile-time counts from the CPU backend, not device times; those
+come from the chip benchmark (benchmarks/chip).
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch llama3-8b \

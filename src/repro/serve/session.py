@@ -30,17 +30,24 @@ Two cache layouts:
 Weights come from a pluggable :mod:`backend <.backends>` (``bf16`` /
 ``q8`` / ``container``).  ``ServeEngine`` is a thin compatibility wrapper
 over this class.
+
+Every step writes ``serve.*`` spans (``jax.profiler.TraceAnnotation``)
+and every jitted program carries a fixed name (``jit_serve_decode``, ...),
+so a profiler trace splits a step's host time and names each program's
+device time; see docs/serving_api.md "Tracing".
 """
 
 from __future__ import annotations
 
 import itertools
+import time
 from collections import deque
 from dataclasses import dataclass, field
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..distributed.sharding import SERVE_RULES, activation_sharding
 from ..models.config import ModelConfig
@@ -91,6 +98,7 @@ class RequestHandle:
     tokens: list = field(default_factory=list)   # generated ids (incl. EOS)
     done: bool = False
     finish_reason: str | None = None     # "eos" | "length"
+    submitted_s: float = 0.0       # time.perf_counter() at submit
     _stream_cursor: int = 0
 
     def new_tokens(self) -> list:
@@ -174,17 +182,19 @@ class ServeSession:
             self._parked: dict = {}             # manual parks, by req id
             self._decode_paged = self._jit(
                 lambda p, pools, pages, tok, pos: decode_step(
-                    p, cfg, pools, pos, tokens=tok, cache_pages=pages))
+                    p, cfg, pools, pos, tokens=tok, cache_pages=pages),
+                "serve_decode")
             self._prefill_fns: dict = {}        # cache_len -> jit
             self._prefill_pad_fns: dict = {}
             self._partial_fns: dict = {}        # n_ctx -> jit
-            self._scatter_paged = jax.jit(self._scatter_paged_impl)
+            self._scatter_paged = jax.jit(_named(self._scatter_paged_impl,
+                                                 "serve_scatter"))
         else:
             self._kv = None
             self._caches = init_cache(cfg, serve_cfg.slots, max_len)
             self._prefill = self._jit(
                 lambda p, toks: prefill(p, cfg, tokens=toks,
-                                        max_len=max_len))
+                                        max_len=max_len), "serve_prefill")
 
             def prefill_padded(p, toks, last_idx):
                 # padded admission: gather the last *real* prompt position
@@ -195,25 +205,30 @@ class ServeSession:
                                                 caches=caches,
                                                 last_index=last_idx)
                 return logits[:, 0, :], new_caches
-            self._prefill_padded = self._jit(prefill_padded)
+            self._prefill_padded = self._jit(prefill_padded,
+                                             "serve_prefill_padded")
             self._decode = self._jit(
                 lambda p, caches, tok, pos: decode_step(p, cfg, caches, pos,
-                                                        tokens=tok))
-            self._scatter = jax.jit(self._scatter_impl)
+                                                        tokens=tok),
+                "serve_decode")
+            self._scatter = jax.jit(_named(self._scatter_impl,
+                                           "serve_scatter"))
 
-    def _jit(self, fn):
-        """``jax.jit`` of a model step.  When the backend placed the
-        weights on a serving mesh, tracing runs under that mesh's serve
-        sharding rules, so activation constraints and mesh-aware attention
-        resolve against the devices the weights live on."""
+    def _jit(self, fn, name: str):
+        """``jax.jit`` of a model step as the program ``jit_<name>`` (its
+        HLO module and its device-trace events carry that name).  When the
+        backend placed the weights on a serving mesh, tracing runs under
+        that mesh's serve sharding rules, so activation constraints and
+        mesh-aware attention resolve against the devices the weights live
+        on."""
         mesh = self.backend.mesh
         if mesh is None:
-            return jax.jit(fn)
+            return jax.jit(_named(fn, name))
 
         def on_mesh(*args):
             with activation_sharding(mesh, SERVE_RULES):
                 return fn(*args)
-        return jax.jit(on_mesh)
+        return jax.jit(_named(on_mesh, name))
 
     @classmethod
     def from_container(cls, cfg: ModelConfig, blob: bytes, *,
@@ -248,7 +263,8 @@ class ServeSession:
             raise ValueError("max_new_tokens must be >= 1")
         req = RequestHandle(id=next(self._ids), prompt=prompt,
                             max_new_tokens=max_new_tokens,
-                            temperature=temperature, seed=seed)
+                            temperature=temperature, seed=seed,
+                            submitted_s=time.perf_counter())
         self._queue.append(req)
         return req
 
@@ -407,32 +423,49 @@ class ServeSession:
         decode step, then evict finished requests.  In slot mode the
         decode batch spans every slot; in paged mode it is compacted to
         the active ones."""
-        if self._paged:
-            return self._step_paged()
+        with TraceAnnotation("serve.step") as span:
+            rows = self.stats["decode_rows"]
+            if self._paged:
+                self._step_paged()
+            else:
+                self._step_slots()
+            span.set_metadata(rows=self.stats["decode_rows"] - rows)
+
+    def _step_slots(self) -> None:
         self._admit()
         if self.num_active == 0:
             self.stats["skipped_all_free_steps"] += 1
             return
-        tok = np.zeros(len(self._slots), np.int32)
-        pos = np.zeros(len(self._slots), np.int32)
-        for i, slot in enumerate(self._slots):
-            if slot.req is not None:
-                tok[i] = slot.next_token
-                pos[i] = slot.pos
+        active = [i for i, s in enumerate(self._slots) if s.req is not None]
+        with TraceAnnotation("serve.pack", rows=len(self._slots)):
+            tok = np.zeros(len(self._slots), np.int32)
+            pos = np.zeros(len(self._slots), np.int32)
+            for i in active:
+                tok[i] = self._slots[i].next_token
+                pos[i] = self._slots[i].pos
+            tok, pos = jnp.asarray(tok), jnp.asarray(pos)
         self.stats["decode_steps"] += 1
         self.stats["decode_rows"] += len(self._slots)
-        self.stats["free_slot_rows"] += len(self._slots) - self.num_active
-        logits, self._caches = self._decode(
-            self.params, self._caches, jnp.asarray(tok), jnp.asarray(pos))
-        logits = np.asarray(logits)
-        for i, slot in enumerate(self._slots):
-            if slot.req is None:
-                continue
-            slot.pos += 1
-            nxt = self._sample(logits[i], slot.req)
-            slot.req.tokens.append(nxt)
-            slot.next_token = nxt
-            self._maybe_evict(slot, i)
+        self.stats["free_slot_rows"] += len(self._slots) - len(active)
+        with TraceAnnotation("serve.dispatch"):
+            logits, self._caches = self._decode(self.params, self._caches,
+                                                tok, pos)
+        self._advance(logits, [(i, i) for i in active])
+
+    def _advance(self, logits, rows: list) -> None:
+        """Copy a decode step's logits to the host and sample each live
+        row's next token into its slot; ``rows`` holds (logits row, slot
+        index) pairs."""
+        with TraceAnnotation("serve.fetch"):
+            logits = np.asarray(logits)
+        with TraceAnnotation("serve.sample"):
+            for j, i in rows:
+                slot = self._slots[i]
+                slot.pos += 1
+                nxt = self._sample(logits[j], slot.req)
+                slot.req.tokens.append(nxt)
+                slot.next_token = nxt
+                self._maybe_evict(slot, i)
 
     def _admit(self) -> None:
         """Admit queued requests onto free slots.  The FIFO prefix sharing
@@ -443,16 +476,21 @@ class ServeSession:
             free = [i for i, s in enumerate(self._slots) if s.req is None]
             if not free:
                 return
-            length = self._bucket_len(self._queue[0].prompt.size)
-            group = []
-            for req in itertools.islice(self._queue, len(free)):
-                if self._bucket_len(req.prompt.size) != length:
-                    break
-                group.append(req)
-            for _ in group:
-                self._queue.popleft()
-            slots_idx = free[:len(group)]
+            with _admit_span(self._queue[0]):
+                self._admit_group(free)
 
+    def _admit_group(self, free: list) -> None:
+        length = self._bucket_len(self._queue[0].prompt.size)
+        group = []
+        for req in itertools.islice(self._queue, len(free)):
+            if self._bucket_len(req.prompt.size) != length:
+                break
+            group.append(req)
+        for _ in group:
+            self._queue.popleft()
+        slots_idx = free[:len(group)]
+
+        with TraceAnnotation("serve.prefill", tokens=len(group) * length):
             toks = np.zeros((len(group), length), np.int32)
             for j, req in enumerate(group):
                 toks[j, :req.prompt.size] = req.prompt
@@ -465,7 +503,9 @@ class ServeSession:
                 logits, caches_g = self._prefill(self.params,
                                                  jnp.asarray(toks))
             self._place(caches_g, slots_idx)
+        with TraceAnnotation("serve.fetch"):
             logits = np.asarray(logits)
+        with TraceAnnotation("serve.sample"):
             for j, req in enumerate(group):
                 i = slots_idx[j]
                 slot = self._slots[i]
@@ -519,37 +559,34 @@ class ServeSession:
             return
         # page-boundary allocation; a slot the pool can't grow parks
         # itself (compressed to host) and re-admits when pressure clears
-        still = []
-        for i in active:
-            if self._kv.ensure_writable(i, self._slots[i].pos):
-                still.append(i)
-            else:
-                self._auto_park(i)
+        with TraceAnnotation("serve.pages"):
+            still = []
+            for i in active:
+                if self._kv.ensure_writable(i, self._slots[i].pos):
+                    still.append(i)
+                else:
+                    self._auto_park(i)
         active = still
         if not active:
             return
         bs = min(1 << (len(active) - 1).bit_length(), len(self._slots))
-        tok = np.zeros(bs, np.int32)
-        pos = np.zeros(bs, np.int32)
-        pages = np.zeros((bs, self._kv.n_max), np.int32)   # pads -> scratch
-        for j, i in enumerate(active):
-            tok[j] = self._slots[i].next_token
-            pos[j] = self._slots[i].pos
-            pages[j] = self._kv.page_row(i)
+        with TraceAnnotation("serve.pack", rows=bs):
+            tok = np.zeros(bs, np.int32)
+            pos = np.zeros(bs, np.int32)
+            pages = np.zeros((bs, self._kv.n_max), np.int32)  # pads: scratch
+            for j, i in enumerate(active):
+                tok[j] = self._slots[i].next_token
+                pos[j] = self._slots[i].pos
+                pages[j] = self._kv.page_row(i)
+            pages, tok, pos = (jnp.asarray(pages), jnp.asarray(tok),
+                               jnp.asarray(pos))
         self.stats["decode_steps"] += 1
         self.stats["decode_rows"] += bs
         self.stats["padded_rows"] += bs - len(active)
-        logits, self._kv.pools = self._decode_paged(
-            self.params, self._kv.pools, jnp.asarray(pages),
-            jnp.asarray(tok), jnp.asarray(pos))
-        logits = np.asarray(logits)
-        for j, i in enumerate(active):
-            slot = self._slots[i]
-            slot.pos += 1
-            nxt = self._sample(logits[j], slot.req)
-            slot.req.tokens.append(nxt)
-            slot.next_token = nxt
-            self._maybe_evict(slot, i)
+        with TraceAnnotation("serve.dispatch"):
+            logits, self._kv.pools = self._decode_paged(
+                self.params, self._kv.pools, pages, tok, pos)
+        self._advance(logits, list(enumerate(active)))
 
     def _admit_paged(self) -> None:
         """Resumes first (FIFO), then fresh admissions — one batch=1
@@ -571,59 +608,69 @@ class ServeSession:
             if not free:
                 return
             req = self._queue[0]
-            # fresh admissions may park a victim slot to make room, but
-            # never while resumes are waiting (no priority inversion)
-            make_room = self._park_victim if not self._resume_q else None
-            min_len = self._bucket_len(req.prompt.size)
-            ctx_len = self._kv.admit(free[0], req.prompt, min_len=min_len,
-                                     make_room=make_room)
-            if ctx_len is None:
-                self.stats["admit_stalls"] += 1
-                return
-            self._queue.popleft()
-            logits_row = self._prefill_paged(free[0], req, ctx_len)
-            self._kv.publish(free[0])
-            slot = self._slots[free[0]]
-            first = self._sample(logits_row, req)
-            req.tokens.append(first)
-            slot.req = req
-            slot.pos = req.prompt.size
-            slot.next_token = first
-            self._maybe_evict(slot, free[0])
+            with _admit_span(req):
+                # fresh admissions may park a victim slot to make room,
+                # but never while resumes are waiting (no priority
+                # inversion)
+                make_room = self._park_victim if not self._resume_q else None
+                min_len = self._bucket_len(req.prompt.size)
+                ctx_len = self._kv.admit(free[0], req.prompt,
+                                         min_len=min_len,
+                                         make_room=make_room)
+                if ctx_len is None:
+                    self.stats["admit_stalls"] += 1
+                    return
+                self._queue.popleft()
+                logits = self._prefill_paged(free[0], req, ctx_len)
+                with TraceAnnotation("serve.fetch"):
+                    logits_row = np.asarray(logits)[0]
+                self._kv.publish(free[0])
+                with TraceAnnotation("serve.sample"):
+                    slot = self._slots[free[0]]
+                    first = self._sample(logits_row, req)
+                    req.tokens.append(first)
+                    slot.req = req
+                    slot.pos = req.prompt.size
+                    slot.next_token = first
+                    self._maybe_evict(slot, free[0])
 
-    def _prefill_paged(self, idx: int, req: RequestHandle,
-                       ctx_len: int) -> np.ndarray:
-        """Prefill into the slot's freshly built page table.  With a
-        shared-prefix hit only the suffix runs (partial prefill over the
-        gathered context pages); otherwise the whole (bucketed) prompt
-        prefills into a contiguous cache that is scattered to the pages."""
+    def _prefill_paged(self, idx: int, req: RequestHandle, ctx_len: int):
+        """Prefill into the slot's freshly built page table; returns the
+        (1, vocab) logits of the last prompt position, on the device.
+        With a shared-prefix hit only the suffix runs (partial prefill
+        over the gathered context pages); otherwise the whole (bucketed)
+        prompt prefills into a contiguous cache that is scattered to the
+        pages."""
         prompt = req.prompt
         page = self._kv.page
         ids = self._kv.slot_ids(idx)
         if ctx_len > 0:
             n_ctx = ctx_len // page
             fn = self._partial_prefill_fn(n_ctx)
-            logits, self._kv.pools = fn(
-                self.params, self._kv.pools, jnp.asarray(ids, jnp.int32),
-                jnp.asarray(prompt[None, ctx_len:]))
+            with TraceAnnotation("serve.prefill",
+                                 tokens=prompt.size - ctx_len):
+                logits, self._kv.pools = fn(
+                    self.params, self._kv.pools, jnp.asarray(ids, jnp.int32),
+                    jnp.asarray(prompt[None, ctx_len:]))
             self.stats["prefix_reused_tokens"] += ctx_len
             self.stats["prefill_tokens"] += prompt.size - ctx_len
-            return np.asarray(logits)[0]
+            return logits
         length = self._bucket_len(prompt.size)
         cache_len = len(ids) * page
-        toks = np.zeros((1, length), np.int32)
-        toks[0, :prompt.size] = prompt
-        if prompt.size < length:
-            logits, caches = self._prefill_pad_fn(cache_len)(
-                self.params, jnp.asarray(toks),
-                jnp.asarray([prompt.size - 1], jnp.int32))
-        else:
-            logits, caches = self._prefill_fn(cache_len)(
-                self.params, jnp.asarray(toks))
-        self._kv.pools = self._scatter_paged(
-            self._kv.pools, caches, jnp.asarray(ids, jnp.int32))
+        with TraceAnnotation("serve.prefill", tokens=length):
+            toks = np.zeros((1, length), np.int32)
+            toks[0, :prompt.size] = prompt
+            if prompt.size < length:
+                logits, caches = self._prefill_pad_fn(cache_len)(
+                    self.params, jnp.asarray(toks),
+                    jnp.asarray([prompt.size - 1], jnp.int32))
+            else:
+                logits, caches = self._prefill_fn(cache_len)(
+                    self.params, jnp.asarray(toks))
+            self._kv.pools = self._scatter_paged(
+                self._kv.pools, caches, jnp.asarray(ids, jnp.int32))
         self.stats["prefill_tokens"] += length
-        return np.asarray(logits)[0]
+        return logits
 
     def _auto_park(self, idx: int) -> None:
         slot = self._slots[idx]
@@ -653,7 +700,8 @@ class ServeSession:
         if fn is None:
             cfg = self.cfg
             fn = self._jit(lambda p, toks: prefill(p, cfg, tokens=toks,
-                                                   max_len=cache_len))
+                                                   max_len=cache_len),
+                           "serve_prefill")
             self._prefill_fns[cache_len] = fn
         return fn
 
@@ -668,7 +716,7 @@ class ServeSession:
                                                 caches=caches,
                                                 last_index=last_idx)
                 return logits[:, 0, :], new_caches
-            fn = self._jit(pad_fn)
+            fn = self._jit(pad_fn, "serve_prefill_padded")
             self._prefill_pad_fns[cache_len] = fn
         return fn
 
@@ -698,7 +746,7 @@ class ServeSession:
                     return pool.at[:, ids[n_ctx:]].set(
                         c[:, n_ctx:].astype(pool.dtype))
                 return logits[:, 0], jax.tree.map(put, pools, newc)
-            fn = self._jit(partial_fn)
+            fn = self._jit(partial_fn, "serve_prefill_partial")
             self._partial_fns[n_ctx] = fn
         return fn
 
@@ -741,3 +789,18 @@ class ServeSession:
             self._rngs[req.id] = rng
         z = logits_row.astype(np.float64) / req.temperature
         return int(np.argmax(z + rng.gumbel(size=z.shape)))
+
+
+def _named(fn, name: str):
+    """``fn`` under ``name``: ``jax.jit`` names its program ``jit_<name>``."""
+    def named(*args):
+        return fn(*args)
+    named.__name__ = named.__qualname__ = name
+    return named
+
+
+def _admit_span(req: RequestHandle) -> TraceAnnotation:
+    """The ``serve.admit`` span of an admission that starts now: the
+    request's id and how long it waited since ``submit``, in µs."""
+    wait_us = int((time.perf_counter() - req.submitted_s) * 1e6)
+    return TraceAnnotation("serve.admit", req=req.id, wait_us=wait_us)
