@@ -52,34 +52,47 @@ def _cache_update(cache_arr, new_vals, cache_pos, delta):
     return cache_arr.at[jnp.arange(b), cp].set(vals[:, 0])
 
 
-def _paged_update_load(pool, new_vals, cache_pos, cache_pages, delta, dtype):
-    """Paged decode: write one token into the page pool, read the batch's
-    logical views back.
+def _paged_update_load(pools, new_vals, cache_pos, cache_pages, layer,
+                       delta, dtype):
+    """Paged decode: write one token into layer ``layer`` of the page pool,
+    read the batch's logical views of that layer back.
 
-    pool (P, page, ...) is the shared hot-page pool (layer axis already
-    consumed by the scan); cache_pages (B, n_max) int32 maps each row's
-    logical page index to a pool page id.  Row ``i``'s new K/V lands in
-    page ``cache_pages[i, pos // page]`` at offset ``pos % page``; the
-    gathered view ``pool[cache_pages]`` reshapes to the row's contiguous
-    (B, n_max*page, ...) cache.  Pool page 0 is the scheduler's scratch
-    page: padding rows point every logical page at it, so their writes
-    collide harmlessly there and never touch a live page.
+    pools (L, P, page, ...) is every layer's hot-page pool, carried whole
+    through the layer scan so the write lands in place; cache_pages
+    (B, n_max) int32 maps each row's logical page index to a pool page id
+    (one id covers every layer's slice of a token page).  Row ``i``'s new
+    K/V lands at ``[layer, cache_pages[i, pos // page], pos % page]``; the
+    view is one gather ``pools[layer, cache_pages]`` reshaped to the row's
+    contiguous (B, n_max*page, ...) cache, so no layer's pool is ever
+    sliced out whole.  Pool page 0 is the scheduler's scratch page:
+    padding rows point every logical page at it, so their writes collide
+    harmlessly there and never touch a live page.
 
-    Returns (updated pool, per-row contiguous values in ``dtype``).
+    The token goes in by rewriting its whole page: on a TPU one token of
+    a page can be a partial tile (the pool's default layout tiles
+    (page, head_dim) when the KV-head count is not a multiple of 8), and
+    writing a partial tile in place makes XLA lay the whole pool out
+    anew around the layer loop.  A page is whole tiles in any layout.
+
+    Returns (updated pools, per-row contiguous values in ``dtype``).
     """
     b = cache_pages.shape[0]
     assert new_vals.shape[1] == 1, "paged cache update is decode-only (S=1)"
-    page_len = pool.shape[1]
+    page_len = pools.shape[2]
     cp = jnp.asarray(cache_pos, jnp.int32)
     if cp.ndim == 0:
         cp = jnp.broadcast_to(cp, (b,))
     pid = jnp.take_along_axis(cache_pages, (cp // page_len)[:, None],
                               axis=1)[:, 0]
-    pool = pool.at[pid, cp % page_len].set(
-        _cache_store(new_vals, pool, delta)[:, 0])
-    view = jnp.take(pool, cache_pages, axis=0)       # (B, n_max, page, ...)
+    vals = _cache_store(new_vals, pools, delta)          # (B, 1, ...)
+    page = pools.at[layer, pid].get(mode="fill")         # (B, page, ...)
+    hit = jnp.arange(page_len)[None, :] == (cp % page_len)[:, None]
+    hit = hit.reshape(b, page_len, *(1,) * (page.ndim - 2))
+    pools = pools.at[layer, pid].set(jnp.where(hit, vals, page))
+    # (B, n_max, page, ...)
+    view = pools.at[layer, cache_pages].get(mode="fill")
     view = view.reshape(b, view.shape[1] * page_len, *view.shape[3:])
-    return pool, _cache_load(view, dtype, delta)
+    return pools, _cache_load(view, dtype, delta)
 
 # attend(impl=...) values -> registry impl names (the historical attend
 # vocabulary predates the kernel registry, so "naive"/"pallas_flash"
@@ -153,14 +166,15 @@ def _attend_on_mesh(op, q, k, v, qpos, kv_len, mesh, rules):
 # ---------------------------------------------------------------------------
 
 def gqa_attention(x, p, cfg, positions, *, cache=None, cache_pos=None,
-                  positions_3d=None, cache_pages=None):
+                  positions_3d=None, cache_pages=None, layer=None):
     """x (B,S,d).  Returns (out (B,S,d), new_cache | None).
 
     Prefill/train: cache None (train) or dict to fill (prefill).
     Decode: S == 1, cache holds (B, Smax, G, D); cache_pos is a scalar
     (whole batch at one offset) or a (B,) int32 vector of per-row offsets
     (ragged continuous batching — see _cache_update).
-    Paged decode: cache leaves are page *pools* (P, page, G, D) and
+    Paged decode: cache leaves are every layer's page *pools*
+    (L, P, page, G, D), ``layer`` is this layer's index into them, and
     cache_pages (B, n_max) int32 maps logical page index -> pool page id
     (see _paged_update_load; the serving page table lives in
     ``repro.serve.kv``).
@@ -190,9 +204,9 @@ def gqa_attention(x, p, cfg, positions, *, cache=None, cache_pos=None,
     delta = cfg.kv_cache_delta
     if cache is not None and cache_pages is not None:      # paged decode
         ck, k = _paged_update_load(cache["k"], k, cache_pos, cache_pages,
-                                   delta, q.dtype)
+                                   layer, delta, q.dtype)
         cv, v = _paged_update_load(cache["v"], v, cache_pos, cache_pages,
-                                   delta, q.dtype)
+                                   layer, delta, q.dtype)
         new_cache = {"k": ck, "v": cv}
         kv_len = jnp.broadcast_to(
             jnp.asarray(cache_pos, jnp.int32) + s, (b,))
@@ -222,11 +236,12 @@ def gqa_attention(x, p, cfg, positions, *, cache=None, cache_pos=None,
 # ---------------------------------------------------------------------------
 
 def mla_attention(x, p, cfg, positions, *, cache=None, cache_pos=None,
-                  cache_pages=None):
+                  cache_pages=None, layer=None):
     """Latent-cache attention: the KV cache stores only (c_kv, k_rope).
 
-    ``cache_pages`` selects the paged-decode path exactly as in
-    :func:`gqa_attention` — the pools are (P, page, R) latent pages."""
+    ``cache_pages`` and ``layer`` select the paged-decode path exactly as
+    in :func:`gqa_attention` — the pools are (L, P, page, R) latent
+    pages."""
     b, s, _ = x.shape
     h = cfg.num_heads
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -252,9 +267,9 @@ def mla_attention(x, p, cfg, positions, *, cache=None, cache_pos=None,
     delta = cfg.kv_cache_delta
     if cache is not None and cache_pages is not None:      # paged decode
         ckv_all, ckv = _paged_update_load(cache["ckv"], ckv, cache_pos,
-                                          cache_pages, delta, x.dtype)
+                                          cache_pages, layer, delta, x.dtype)
         kr_all, kr = _paged_update_load(cache["kr"], kr, cache_pos,
-                                        cache_pages, delta, x.dtype)
+                                        cache_pages, layer, delta, x.dtype)
         new_cache = {"ckv": ckv_all, "kr": kr_all}
         kv_len = jnp.broadcast_to(
             jnp.asarray(cache_pos, jnp.int32) + s, (b,))

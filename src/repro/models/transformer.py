@@ -267,20 +267,21 @@ def init_params(cfg: ModelConfig, key) -> dict:
 # ---------------------------------------------------------------------------
 
 def _attn_dispatch(x, lp, cfg, positions, pos3d, cache, cache_pos,
-                   cache_pages=None):
+                   cache_pages=None, layer=None):
     if cfg.attention == "mla":
         return mla_attention(x, lp, cfg, positions, cache=cache,
-                             cache_pos=cache_pos, cache_pages=cache_pages)
+                             cache_pos=cache_pos, cache_pages=cache_pages,
+                             layer=layer)
     return gqa_attention(x, lp, cfg, positions, cache=cache,
                          cache_pos=cache_pos, positions_3d=pos3d,
-                         cache_pages=cache_pages)
+                         cache_pages=cache_pages, layer=layer)
 
 
 def _dense_block(x, lp, cfg, positions, pos3d, cache, cache_pos,
-                 cache_pages=None):
+                 cache_pages=None, layer=None):
     a, new_cache = _attn_dispatch(_norm(x, lp["attn_norm"], cfg), lp["attn"],
                                   cfg, positions, pos3d, cache, cache_pos,
-                                  cache_pages)
+                                  cache_pages, layer)
     x = constrain(x + a, "batch", "seq", None)
     x = x + swiglu_mlp(_norm(x, lp["mlp_norm"], cfg), lp["mlp"], cfg.act,
                        policy=cfg.kernels)
@@ -289,18 +290,18 @@ def _dense_block(x, lp, cfg, positions, pos3d, cache, cache_pos,
 
 
 def _moe_layer_block(x, lp, cfg, positions, pos3d, cache, cache_pos,
-                     cache_pages=None):
+                     cache_pages=None, layer=None):
     a, new_cache = _attn_dispatch(_norm(x, lp["attn_norm"], cfg), lp["attn"],
                                   cfg, positions, pos3d, cache, cache_pos,
-                                  cache_pages)
+                                  cache_pages, layer)
     x = constrain(x + a, "batch", "seq", None)
     m, aux = moe_block(_norm(x, lp["mlp_norm"], cfg), lp["moe"], cfg)
     return constrain(x + m, "batch", "seq", None), new_cache, aux
 
 
 def _ssm_block(x, lp, cfg, positions, pos3d, cache, cache_pos,
-               cache_pages=None):
-    del positions, pos3d, cache_pos, cache_pages
+               cache_pages=None, layer=None):
+    del positions, pos3d, cache_pos, cache_pages, layer
     m, new_cache = mamba2_mixer(_norm(x, lp["norm"], cfg), lp["mixer"], cfg,
                                 cache=cache)
     return constrain(x + m, "batch", "seq", None), new_cache, \
@@ -326,9 +327,12 @@ def _scan_stack(x, stacked, block, cfg, positions, pos3d, caches, cache_pos,
 
     q8-quantized serving weights are dequantized *inside* the loop body, so
     HBM reads of the stacked parameters stay int8 (1 B/param).  Under
-    paged decode the per-layer cache leaf is that layer's page *pool* and
-    ``cache_pages`` (shared across layers — one page table entry covers
-    every layer's slice of a token page) rides in the closure."""
+    paged decode the stacked page *pools* (L, P, page, ...) ride whole in
+    the carry, next to the layer index, and each layer writes and reads
+    them at that index: a pool passed as ``xs`` would be sliced out per
+    layer and re-stacked as ``ys``, copying it every step.  ``cache_pages``
+    (shared across layers — one page table entry covers every layer's
+    slice of a token page) rides in the closure."""
     dt = jnp.dtype(cfg.compute_dtype)
     body = _maybe_remat(
         functools.partial(block, cfg=cfg, positions=positions, pos3d=pos3d,
@@ -341,6 +345,19 @@ def _scan_stack(x, stacked, block, cfg, positions, pos3d, caches, cache_pos,
             return (h2, aux + a), None
         (x, aux), _ = lax.scan(f, (x, jnp.zeros((), jnp.float32)), stacked)
         return x, None, aux
+
+    if cache_pages is not None:
+        def f(carry, xs):
+            h, aux, pools = carry
+            lp, layer = xs
+            h2, pools, a = body(h, _fused_layer_params(lp, dt), cache=pools,
+                                layer=layer)
+            return (h2, aux + a, pools), None
+        n_layers = jax.tree.leaves(caches)[0].shape[0]
+        (x, aux, pools), _ = lax.scan(
+            f, (x, jnp.zeros((), jnp.float32), caches),
+            (stacked, jnp.arange(n_layers, dtype=jnp.int32)))
+        return x, pools, aux
 
     def f(carry, xs):
         h, aux = carry

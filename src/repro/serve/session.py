@@ -180,15 +180,18 @@ class ServeSession:
                 restore_workers=serve_cfg.kv_restore_workers)
             self._resume_q: deque = deque()     # (req, parked, pos, next)
             self._parked: dict = {}             # manual parks, by req id
+            # the decode and scatter programs donate the pool: each writes
+            # its pages in place, and the caller rebinds ``_kv.pools``
             self._decode_paged = self._jit(
                 lambda p, pools, pages, tok, pos: decode_step(
                     p, cfg, pools, pos, tokens=tok, cache_pages=pages),
-                "serve_decode")
+                "serve_decode", donate_argnums=1)
             self._prefill_fns: dict = {}        # cache_len -> jit
             self._prefill_pad_fns: dict = {}
             self._partial_fns: dict = {}        # n_ctx -> jit
-            self._scatter_paged = jax.jit(_named(self._scatter_paged_impl,
-                                                 "serve_scatter"))
+            self._scatter_paged = jax.jit(
+                _named(self._scatter_paged_impl, "serve_scatter"),
+                donate_argnums=0)
         else:
             self._kv = None
             self._caches = init_cache(cfg, serve_cfg.slots, max_len)
@@ -214,21 +217,21 @@ class ServeSession:
             self._scatter = jax.jit(_named(self._scatter_impl,
                                            "serve_scatter"))
 
-    def _jit(self, fn, name: str):
+    def _jit(self, fn, name: str, donate_argnums=()):
         """``jax.jit`` of a model step as the program ``jit_<name>`` (its
-        HLO module and its device-trace events carry that name).  When the
-        backend placed the weights on a serving mesh, tracing runs under
-        that mesh's serve sharding rules, so activation constraints and
-        mesh-aware attention resolve against the devices the weights live
-        on."""
+        HLO module and its device-trace events carry that name), donating
+        ``donate_argnums``.  When the backend placed the weights on a
+        serving mesh, tracing runs under that mesh's serve sharding rules,
+        so activation constraints and mesh-aware attention resolve against
+        the devices the weights live on."""
         mesh = self.backend.mesh
         if mesh is None:
-            return jax.jit(_named(fn, name))
+            return jax.jit(_named(fn, name), donate_argnums=donate_argnums)
 
         def on_mesh(*args):
             with activation_sharding(mesh, SERVE_RULES):
                 return fn(*args)
-        return jax.jit(_named(on_mesh, name))
+        return jax.jit(_named(on_mesh, name), donate_argnums=donate_argnums)
 
     @classmethod
     def from_container(cls, cfg: ModelConfig, blob: bytes, *,

@@ -155,3 +155,44 @@ def test_qwen_q8_decode_step_fits_one_chip(chip):
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes)
     assert total < HBM_BYTES, f"{total / 2**30:.2f} GiB > 16 GiB"
+
+
+@pytest.mark.parametrize("name,pool_pages", [("qwen1.5-4b", 481),
+                                             ("mistral-nemo-12b", 641)])
+def test_paged_decode_updates_the_pool_in_place(chip, name, pool_pages):
+    """The served paged decode step at full width and the benchmark's pool
+    (page 16, 80 pages per row, 2 rows), donating the pool as the session
+    does: the output pool aliases the input, and no instruction copies the
+    stacked pool or materialises one layer's pool.  qwen's 20 KV heads
+    give its pool a layout that tiles (page, head_dim), in which a
+    one-token write is a partial tile."""
+    import re
+    cfg = configs.get(name).replace(kernels=KernelPolicy(platform="tpu"))
+    page, n_max, rows = 16, 80, 2
+    on_chip = (lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                              sharding=chip))
+    params = jax.tree.map(on_chip, jax.eval_shape(lambda: quantize_tree_q8(
+        init_params(cfg, jax.random.PRNGKey(0)))))
+    pools = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: init_cache(cfg, pool_pages, page)))
+    ids = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=chip)
+           for s in ((rows, n_max), (rows,), (rows,))]
+    compiled = jax.jit(
+        lambda p, pl, pg, tok, pos: decode_step(p, cfg, pl, pos, tokens=tok,
+                                                cache_pages=pg),
+        donate_argnums=1).lower(params, pools, *ids).compile()
+    pool_bytes = sum(np.prod(l.shape) * l.dtype.itemsize
+                     for l in jax.tree.leaves(pools))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes / 4
+    layer = f"{pool_pages},{page},{cfg.num_kv_heads},{cfg.head_dim}]"
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \S+\[([0-9,]+\])\S* (\S+)\(",
+                     line)
+        if m is None:
+            continue
+        inst, shape, op = m.groups()
+        assert shape not in (layer, "1," + layer), line[:160]
+        if shape == f"{cfg.num_layers},{layer}":
+            assert op != "copy" and not inst.startswith("copy"), line[:160]
