@@ -2,11 +2,12 @@
 
 Once the window has closed, a sample of the requests it finished, drawn
 from the seed and always holding the longest one, goes through the plain
-reference (``reference.py``) with its prompt and the tokens it was
-served.  The number compared is the widest gap by which a served token's
-reference logit lies below the reference's best logit at that position:
-greedy decoding at the configuration's precision keeps it near zero,
-and a lower precision or a wrong token opens it.
+reference (the model family's, ``families/``, with ``reference.py``)
+with its prompt and the tokens it was served.  The number compared is
+the widest gap by which a served token's reference logit lies below the
+reference's best logit at that position: greedy decoding at the
+configuration's precision keeps it near zero, and a lower precision or a
+wrong token opens it.
 
 Every finished request must also hold exactly the tokens it asked for,
 each a vocabulary id (``bad_requests``, limit 0).
@@ -66,14 +67,16 @@ def batch(chosen: list, k: int, max_len: int, max_out: int):
     return tokens, np.asarray(rows, np.int32), np.asarray(served, np.int32)
 
 
-def logit_gaps(tree, sizes: dict, chosen: list, mix: dict, k: int,
+def logit_gaps(family, tree, sizes: dict, chosen: list, mix: dict, k: int,
                control: bool = False) -> dict:
     """Widest reference-logit gap of the served tokens (``program``) and,
     with ``control``, of the fp8 control's first choices, over the same
-    prompts and served tokens; plus how many tokens were compared."""
+    prompts and served tokens, by ``family``'s reference; plus how many
+    tokens were compared."""
     tokens, rows, served = batch(chosen, k, mix["max_len"],
                                  mix["output_len"]["max"])
-    g = reference.gaps(tree, sizes, tokens, rows, served, control=control)
+    g = reference.gaps(family, tree, sizes, tokens, rows, served,
+                       control=control)
     out = {name: float(np.max(np.asarray(v))) for name, v in g.items()}
     out["tokens"] = int(sum(len(s.handle.tokens) for s in chosen))
     return out

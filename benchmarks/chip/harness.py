@@ -89,22 +89,19 @@ def _policy(dep: dict, rehearse: bool):
 
 def model(cell: spec.Cell, rehearse: bool):
     """(ModelConfig, sizes): the registry entry with the published
-    rope_theta and norm epsilon, its widths checked against the file.
-    A rehearsal takes the registry's smoke preset at the full model's
-    dtypes."""
+    rope_theta and norm epsilon, its widths checked against the file by
+    the cell's family.  A rehearsal takes the registry's smoke preset at
+    the full model's dtypes."""
     from repro import configs
-    conf = cell.config
-    sizes = cell.sizes
+    conf, fam, sizes = cell.config, cell.family, cell.sizes
+    cfg = configs.get(conf["model"])
     if rehearse:
-        full = configs.get(conf["model"])
         cfg = configs.get(conf["model"], smoke=True).replace(
-            param_dtype=full.param_dtype, compute_dtype=full.compute_dtype)
-        sizes = dict(sizes, **{k: getattr(cfg, k) for k in spec.WIDTHS})
+            param_dtype=cfg.param_dtype, compute_dtype=cfg.compute_dtype)
+        sizes = dict(sizes, **fam.smoke_sizes(cfg))
     else:
-        cfg = configs.get(conf["model"])
-        wrong = {k: (getattr(cfg, k), sizes[k]) for k in spec.WIDTHS
-                 if getattr(cfg, k) != sizes[k]}
-        if wrong or cfg.qkv_bias != sizes["qkv_bias"] or cfg.family != "dense":
+        wrong = fam.check_registry(cfg, sizes)
+        if wrong:
             raise SystemExit(f"{conf['model']}: registry differs from the "
                              f"configuration file: {wrong}")
     cfg = cfg.replace(kernels=_policy(conf["deployment"], rehearse),
@@ -136,7 +133,8 @@ class Bench:
         self.slots = spec.slots(self.cell.config, self.cell.mix)
         t0 = time.perf_counter()
         self.tree = jax.block_until_ready(
-            weights.make_weights(self.sizes, dep["weight_seed"]))
+            weights.make_weights(self.cell.family, self.sizes,
+                                 dep["weight_seed"]))
         n_bytes = sum(x.nbytes for x in jax.tree.leaves(self.tree))
         log(f"weights: {n_bytes / 2**30:.3f} GiB in "
             f"{time.perf_counter() - t0:.2f}s")
@@ -221,8 +219,9 @@ class Bench:
         chosen = check.sample(w, k, seed)
         out = {"bad_requests": check.bad_requests(w, self.sizes["vocab_size"])}
         if chosen:
-            out.update(check.logit_gaps(self.tree, self.sizes, chosen,
-                                        self.mix, k, control=control))
+            out.update(check.logit_gaps(self.cell.family, self.tree,
+                                        self.sizes, chosen, self.mix, k,
+                                        control=control))
         return out
 
 

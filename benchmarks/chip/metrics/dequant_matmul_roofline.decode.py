@@ -6,5 +6,4 @@ steps, in percent."""
 
 
 def read(run):
-    return run.kernel_share("dequant_matmul", run.of_kind(decode_only=True),
-                            run.dequant_work)
+    return run.kernel_share("dequant_matmul", run.of_kind(decode_only=True))

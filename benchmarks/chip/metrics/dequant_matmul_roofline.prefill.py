@@ -4,5 +4,4 @@ decode rows of the same step)."""
 
 
 def read(run):
-    return run.kernel_share("dequant_matmul",
-                            run.of_kind(decode_only=False), run.dequant_work)
+    return run.kernel_share("dequant_matmul", run.of_kind(decode_only=False))

@@ -3,13 +3,11 @@
 token per row) over the wall time of the decode-only step spans times
 the chip's HBM bandwidth, in percent."""
 
-import work
-
 
 def read(run):
     steps = run.of_kind(decode_only=True)
     if not steps:
         return None
-    need = sum(work.decode_step_bytes(run.sizes, st.ctxs) for st, _ in steps)
+    need = sum(run.decode_bytes(st) for st, _ in steps)
     secs = run.seconds(sp for _, sp in steps)
     return 100.0 * need / (secs * run.peaks["hbm_bytes_per_s"])

@@ -1,16 +1,16 @@
-"""Plain float32 reference of the dense decoder family, and its control.
+"""Plain float32 reference of the served models, and its control: what
+every model family shares.
 
-Written from the published architecture (Qwen2 / Mistral: pre-norm
-RMSNorm, rotate-half RoPE, grouped-query attention with an optional
-q/k/v bias, SwiGLU MLP, untied head) in straightforward ``jax.numpy``.
-It imports nothing of the program: it reads the benchmark's own q8 tree
-(``weights.py``) dequantized to f32 and the sizes of the configuration
-file, and runs every matmul at ``jax.default_matmul_precision("highest")``.
-
-It runs a whole padded batch of sequences layer by layer (one jitted
-layer program, indexed into the stacked weights), attention in query
-blocks, and the head in vocabulary blocks, so it fits beside the served
-weights on one chip.  Only the rows asked for reach the head.
+Each family (``families/<family>.py``) writes its own forward from the
+published architecture in straightforward ``jax.numpy`` with the pieces
+here: ``hidden_rows`` runs a whole padded batch of sequences layer by
+layer (one jitted layer program, indexed into the stacked weights), and
+``head`` the untied head in vocabulary blocks (``_head``), so it fits
+beside the served weights on one chip; only the rows asked for reach the
+head.  Nothing here imports the program: it reads the benchmark's own q8
+tree (``weights.py``) dequantized to f32 and the sizes of the
+configuration file, and every matmul runs at
+``jax.default_matmul_precision("highest")``.
 
 ``quant="fp8"`` is the control: the same forward computed one precision
 below the configuration's bf16, with every matmul input (activations
@@ -85,32 +85,6 @@ def _attention(q, k, v, quant):
     return out.reshape(t, h * d)
 
 
-@functools.partial(jax.jit, static_argnames=("s", "quant"))
-def _layer(x, layers, index, s, quant):
-    s = dict(s)
-    lw = jax.tree.map(lambda a: lax.dynamic_index_in_dim(a, index, 0, False),
-                      layers)
-    b, t, _ = x.shape
-    h, g, dh = s["num_heads"], s["num_kv_heads"], s["head_dim"]
-    at = lw["attn"]
-    a = _rms(x, lw["attn_norm"], s["norm_eps"])
-    q, k, v = (_mm(a, at[n], quant) for n in ("wq", "wk", "wv"))
-    if s.get("qkv_bias"):
-        q = q + at["bq"].astype(jnp.float32)
-        k = k + at["bk"].astype(jnp.float32)
-        v = v + at["bv"].astype(jnp.float32)
-    q = _rope(q.reshape(b, t, h, dh), s["rope_theta"])
-    k = _round(_rope(k.reshape(b, t, g, dh), s["rope_theta"]), quant)
-    v = _round(v.reshape(b, t, g, dh), quant)
-    o = jax.vmap(lambda qq, kk, vv: _attention(qq, kk, vv, quant))(q, k, v)
-    x = x + _mm(o, at["wo"], quant)
-    m = _rms(x, lw["mlp_norm"], s["norm_eps"])
-    mlp = lw["mlp"]
-    hid = jax.nn.silu(_mm(m, mlp["w_gate"], quant)) * _mm(m, mlp["w_up"],
-                                                          quant)
-    return x + _mm(hid, mlp["w_down"], quant)
-
-
 @jax.jit
 def _embed(embed, tokens):
     rows = jnp.take(embed["q8"], tokens, axis=0).astype(jnp.float32)
@@ -151,33 +125,18 @@ def _head(hidden, head, targets, quant):
     return best, arg, at
 
 
-def hidden_rows(tree, s: dict, tokens, rows, quant=None):
-    """Final-normed hidden states of ``rows`` (flat indices into the
-    (B, T) batch) after a full causal forward over ``tokens``."""
-    key = tuple(sorted(s.items()))
-    with jax.default_matmul_precision("highest"):
-        x = _embed(tree["embed"], tokens)
-        for i in range(s["num_layers"]):
-            x = _layer(x, tree["layers"], jnp.int32(i), key, quant)
-        return _final(x, rows, tree["final_norm"], s["norm_eps"])
-
-
-def head(tree, hidden, targets, quant=None):
-    with jax.default_matmul_precision("highest"):
-        return _head(hidden, tree["head"], targets, quant)
-
-
-def gaps(tree, s: dict, tokens, rows, served, control: bool = False):
+def gaps(family, tree, s: dict, tokens, rows, served, control: bool = False):
     """By how much the reference's logit of each served token lies below
     its best logit (``program``), and, with ``control``, the same gap for
-    the token the fp8 control puts first (``control``)."""
-    h = hidden_rows(tree, s, tokens, rows)
-    best, _, at = head(tree, h, served[:, None])
+    the token the fp8 control puts first (``control``).  ``family`` (a
+    module of ``families/``) runs the forward and the head."""
+    h = family.hidden_rows(tree, s, tokens, rows)
+    best, _, at = family.head(tree, h, served[:, None])
     out = {"program": best - at[:, 0]}
     if control:
-        hc = hidden_rows(tree, s, tokens, rows, quant="fp8")
-        _, arg_c, _ = head(tree, hc, served[:, None], quant="fp8")
+        hc = family.hidden_rows(tree, s, tokens, rows, quant="fp8")
+        _, arg_c, _ = family.head(tree, hc, served[:, None], quant="fp8")
         del hc
-        _, _, at_c = head(tree, h, arg_c[:, None])
+        _, _, at_c = family.head(tree, h, arg_c[:, None])
         out["control"] = best - at_c[:, 0]
     return out

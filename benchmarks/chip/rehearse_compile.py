@@ -89,10 +89,11 @@ def main(argv=None) -> int:
     print(f"{args.workload}: slots={slots} max_len={mix['max_len']} "
           f"pool_pages={pool_pages} page={page}", flush=True)
     key = on_chip(jax.eval_shape(lambda: weights.seed_key(0)))
-    compile_("weights", lambda k: weights.make_tree(sizes, k), key)
+    make_tree = cell.family.make_tree
+    compile_("weights", lambda k: make_tree(sizes, k), key)
 
     params = on_chip(jax.eval_shape(
-        lambda k: weights.make_tree(sizes, k), weights.seed_key(0)))
+        lambda k: make_tree(sizes, k), weights.seed_key(0)))
     pools = on_chip(jax.eval_shape(lambda: init_cache(cfg, pool_pages, page)))
     i32 = jnp.int32
 

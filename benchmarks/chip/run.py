@@ -66,7 +66,8 @@ def traced_metrics(bench, w, trace_dir):
     start, end = ((min(sp.start for sp in tr.spans),
                    max(sp.end for sp in tr.spans)) if tr.spans
                   else tr.window())
-    run = readers.Traced(bench.sizes, spec.peaks(bench.devices[0].device_kind),
+    run = readers.Traced(bench.cell.family, bench.sizes,
+                         spec.peaks(bench.devices[0].device_kind),
                          bench.mix["prefill_buckets"], steps, tr, start, end)
     metrics = {}
     read = readers.load_readers([m["name"] for m in bench.cell.per_layer])

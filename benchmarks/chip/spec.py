@@ -4,34 +4,58 @@ A cell ``<config>.<traffic>`` of ``BENCHMARK.json``'s ``workloads`` names
 three data files beside this module:
 
 ``configs/<config>.json``  the model (its published config under
-                           ``published``, the program's registry id under
-                           ``model``) and the deployment (residency, kernel
-                           policy, KV page size and pool, weight seed)
+                           ``published``, its architecture, the program's
+                           registry id under ``model``) and the deployment
+                           (residency, kernel policy, KV page size and
+                           pool, weight seed)
 ``traffic/<mix>.json``     the mix (see ``traffic.py``)
 ``cells/<cell>.json``      the load level and the output check's limit
 
-and each per-layer metric a reader ``metrics/<metric>.py``.  Adding a
-cell, a configuration, a mix or a metric adds files; none is edited.
+and each per-layer metric a reader ``metrics/<metric>.py``.  A model
+family is a file too: ``families/<family>.py``, picked by the
+configuration's ``architecture.family`` and loaded by its path.  It
+provides, with ``s`` its sizes:
+
+``sizes(config)``                 the published keys it reads, ``reduced``
+                                  applied last (a size its source lacks
+                                  comes from ``assumed``); ``vocab_size``
+                                  among them, and ``rope_theta`` and
+                                  ``norm_eps``, which the harness serves
+``check_registry(cfg, s)``        {key: (registry, file)} of the widths
+                                  and flags in which the registry entry
+                                  differs from the sizes
+``smoke_sizes(cfg)``              the widths of the smoke preset
+                                  (``--rehearse``)
+``make_tree(s, key)``             the seeded q8 serving tree, in the
+                                  program's layout (``weights.py``)
+``hidden_rows``, ``head``         the plain f32 reference and, with
+                                  ``quant="fp8"``, its control
+                                  (``reference.py``)
+``decode_row_flops(s, ctx)``,     the work of a step (``work.py``)
+``prefill_flops(s, n)``,
+``decode_step_bytes(s, ctxs)``,
+``resident_weight_bytes(s)``,
+``kernel_work(s, kernel, step, bucket)``
+``KERNELS`` (optional)            trace patterns of kernels only this
+                                  family runs, as ``readers.KERNELS``
+
+Adding a cell, a configuration, a family, a mix or a metric adds files;
+none is edited.
 """
 
 from __future__ import annotations
 
+import functools
+import importlib.util
 import json
 from dataclasses import dataclass
 from pathlib import Path
+from types import ModuleType
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
 
-# published (Hugging Face) key -> size key the harness uses
-_PUBLISHED = {"num_hidden_layers": "num_layers", "hidden_size": "d_model",
-              "num_attention_heads": "num_heads",
-              "num_key_value_heads": "num_kv_heads",
-              "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-              "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps"}
-# sizes that must equal the program's registry entry
-WIDTHS = ("num_layers", "d_model", "num_heads", "num_kv_heads", "head_dim",
-          "d_ff", "vocab_size")
+FAMILIES = HERE / "families"
 
 
 @dataclass
@@ -42,10 +66,8 @@ class Cell:
     mix: dict
     cell: dict
     benchmark: dict
-
-    @property
-    def sizes(self) -> dict:
-        return model_sizes(self.config)
+    family: ModuleType       # families/<architecture.family>.py
+    sizes: dict              # family.sizes(config)
 
     @property
     def end_to_end(self) -> list[dict]:
@@ -63,15 +85,23 @@ def _json(path: Path) -> dict:
         return json.load(f)
 
 
-def model_sizes(config: dict) -> dict:
-    """The sizes the harness, the work counts and the reference use, from
-    the configuration file's published config plus ``assumed`` values."""
-    pub = config["published"]
-    s = {ours: pub[theirs] for theirs, ours in _PUBLISHED.items()}
-    s["head_dim"] = pub.get("head_dim") or s["d_model"] // s["num_heads"]
-    s["qkv_bias"] = bool(config["architecture"]["qkv_bias"])
-    s.update(config.get("reduced", {}))
-    return s
+def module(path: Path, name: str) -> ModuleType:
+    """The Python file ``path``, loaded as a module named ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@functools.cache
+def family(name: str, where: Path = FAMILIES) -> ModuleType:
+    """The family module ``<where>/<name>.py``, loaded once per process.
+    ``where`` is for tests; a run always reads ``families/``."""
+    path = where / f"{name}.py"
+    if not path.is_file():
+        raise SystemExit(f"no family module {path} for architecture.family "
+                         f"{name!r}")
+    return module(path, f"family_{name}")
 
 
 def load(workload: str, root: Path = ROOT) -> Cell:
@@ -84,7 +114,9 @@ def load(workload: str, root: Path = ROOT) -> Cell:
     config = _json(HERE / "configs" / f"{entry['config']}.json")
     mix = _json(HERE / "traffic" / f"{entry['traffic']}.json")
     cell = _json(HERE / "cells" / f"{workload}.json")
-    return Cell(workload, entry["chips"], config, mix, cell, bench)
+    fam = family(config["architecture"]["family"])
+    return Cell(workload, entry["chips"], config, mix, cell, bench, fam,
+                fam.sizes(config))
 
 
 def peaks(device_kind: str) -> dict:

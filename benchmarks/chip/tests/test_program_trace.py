@@ -63,7 +63,7 @@ def toy(skew=0.0):
 
 def _read(tr, steps, start, end, cell="mistral-nemo-12b-q8.chat"):
     c = spec.load(cell)
-    run = readers.Traced(c.sizes, spec.peaks("TPU v5 lite"),
+    run = readers.Traced(c.family, c.sizes, spec.peaks("TPU v5 lite"),
                          c.mix["prefill_buckets"], steps, tr, start, end)
     return {n: f(run) for n, f in readers.load_readers(
         [m["name"] for m in c.per_layer]).items()}

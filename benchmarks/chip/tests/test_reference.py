@@ -8,7 +8,6 @@ import numpy as np
 import pytest
 
 import harness
-import reference
 import spec
 import weights
 from repro.serve.session import ServeConfig, ServeSession
@@ -25,7 +24,7 @@ def test_reference_matches_prefill_then_paged_decode(config):
     cell = spec.load(f"{config}.chat")
     cfg, sizes = harness.model(cell, rehearse=True)
     cfg = cfg.replace(param_dtype="float32", compute_dtype="float32")
-    tree = weights.make_weights(sizes, 3)
+    tree = weights.make_weights(cell.family, sizes, 3)
     session = ServeSession(cfg, tree, backend="q8", serve_cfg=ServeConfig(
         slots=2, max_len=64, kv_page_size=16, kv_prefix_sharing=False,
         prefill_buckets=(16, 32)))
@@ -51,10 +50,10 @@ def test_reference_matches_prefill_then_paged_decode(config):
             if rid == h.id:
                 rows.append(b * 64 + p.size - 1 + j)
                 want.append(logits)
-    hidden = reference.hidden_rows(tree, sizes, jnp.asarray(tokens),
-                                   jnp.asarray(rows, jnp.int32))
+    hidden = cell.family.hidden_rows(tree, sizes, jnp.asarray(tokens),
+                                     jnp.asarray(rows, jnp.int32))
     targets = np.broadcast_to(np.arange(v), (len(rows), v))
-    _, _, got = reference.head(tree, hidden, jnp.asarray(targets))
+    _, _, got = cell.family.head(tree, hidden, jnp.asarray(targets))
     got, want = np.asarray(got), np.stack(want)
     assert len(rows) == 18
     assert np.abs(want).max() > 1.0       # logits of unit scale

@@ -45,11 +45,11 @@ def test_idle_gaps_are_named_by_host_span():
 
 def test_readers_on_a_toy_trace():
     tr = toy()
-    sizes = spec.load("qwen1.5-4b-q8.chat").sizes
+    cell = spec.load("qwen1.5-4b-q8.chat")
     steps = [(Step(0, 2, 0, [], [100, 200]), tr.steps()[0]),
              (Step(1, 1, 128, [100], [300]), tr.steps()[1])]
-    run = readers.Traced(sizes, spec.peaks("TPU v5 lite"), [128], steps, tr,
-                         0, 17 * MS)
+    run = readers.Traced(cell.family, cell.sizes, spec.peaks("TPU v5 lite"),
+                         [128], steps, tr, 0, 17 * MS)
     got = {n: f(run) for n, f in readers.load_readers(
         [m["name"] for m in spec.load("qwen1.5-4b-q8.chat").benchmark[
             "per_layer"]]).items()}
@@ -74,7 +74,7 @@ def test_readers_on_a_recorded_v5e_trace():
     assert len(steps) == 8 and all(st.decode_only for st, _ in steps)
     cell = spec.load(kept["workload"])
     start, end = kept["window_ns"]
-    run = readers.Traced(cell.sizes, spec.peaks("TPU v5 lite"),
+    run = readers.Traced(cell.family, cell.sizes, spec.peaks("TPU v5 lite"),
                          cell.mix["prefill_buckets"], steps, tr, start, end)
     got = {n: f(run) for n, f in readers.load_readers(
         [m["name"] for m in cell.benchmark["per_layer"]]).items()}

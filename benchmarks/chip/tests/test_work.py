@@ -1,5 +1,5 @@
-"""work.py's operation counts against XLA's own cost analysis of the
-program's code, at smoke sizes on the CPU."""
+"""work.py's and the dense family's operation counts against XLA's own
+cost analysis of the program's code, at smoke sizes on the CPU."""
 
 import jax
 import jax.numpy as jnp
@@ -12,6 +12,8 @@ from repro import configs
 from repro.kernels.dequant_matmul.ref import dequant_matmul_ref
 from repro.kernels.flash_attention.ref import flash_attention_ref
 from repro.models.transformer import decode_step, init_cache, prefill
+
+DENSE = spec.family("dense")
 
 
 def flops(fn, *args):
@@ -51,9 +53,9 @@ def test_model_steps(name):
     # one layer: XLA's cost analysis counts a scan body once, whatever
     # the trip count
     cfg = configs.get(name, smoke=True).replace(num_layers=1)
-    s = {k: getattr(cfg, k) for k in spec.WIDTHS}
+    s = DENSE.smoke_sizes(cfg)
     s["qkv_bias"] = cfg.qkv_bias
-    tree = jax.eval_shape(lambda: weights.make_weights(s, 0))
+    tree = jax.eval_shape(lambda: weights.make_weights(DENSE, s, 0))
     b, t = 4, 64
     caches = jax.eval_shape(lambda: init_cache(cfg, b, t))
     zeros = jnp.zeros((b,), jnp.int32)
@@ -62,12 +64,12 @@ def test_model_steps(name):
                 tree, caches, zeros, zeros)
     # decode attends over the whole cache (t keys); XLA adds the weights'
     # dequantize (1/(2b) of the matmuls at b rows), norms and softmax
-    want = b * work.decode_row_flops(s, t)
+    want = b * DENSE.decode_row_flops(s, t)
     assert want <= got <= 1.3 * want
     got = flops(lambda p, tok: prefill(p, cfg, tokens=tok), tree,
                 jnp.zeros((1, t), jnp.int32))
     # the prefill's attention computes the whole t x t block and masks,
     # against the causal half counted as work
-    full = work.prefill_flops(s, t) + 4.0 * s["num_heads"] * s[
+    full = DENSE.prefill_flops(s, t) + 4.0 * s["num_heads"] * s[
         "head_dim"] * (t * t - work.causal_pairs(t, t))
     assert full <= got <= 1.15 * full
